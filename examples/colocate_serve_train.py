@@ -20,6 +20,8 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 import argparse
 import json
 
+import jax
+
 from repro.launch.serve import serve
 from repro.obs import ObsHub, prometheus_text
 
@@ -37,7 +39,8 @@ def main() -> None:
                 chaos=args.chaos, failover=args.failover)
     print(json.dumps(out, indent=1))
     print(f"\nserved {out['requests']} requests "
-          f"(p99 {out['p99_ms']:.0f} ms on CPU-interpret) while the "
+          f"(p99 {out['p99_ms']:.0f} ms, host-side wall time on "
+          f"{jax.devices()[0].platform}) while the "
           f"best-effort trainer completed {out['be_quanta']} quanta "
           f"in serving idle gaps")
     if args.chaos:

@@ -64,7 +64,7 @@ class KernelDescriptor:
     scratch_shapes: Tuple[Any, ...] = ()
     flops: float = 0.0                  # per full launch (device model input)
     bytes_accessed: float = 0.0
-    interpret: bool = True              # CPU container; False on real TPU
+    interpret: Optional[bool] = None    # None: decided by resolve_interpret
     revisits_output: bool = False       # sequential axis accumulates into out
 
     # -- derived -------------------------------------------------------------
@@ -94,6 +94,40 @@ class KernelDescriptor:
         return dataclasses.replace(self, **kw)
 
 
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Whether a ``pallas_call`` runs in interpret mode.
+
+    The one place that decides it: an explicit flag wins (tests); ``None``
+    means compiled on a TPU backend and interpreted on the CPU. Any other
+    backend raises rather than silently interpreting on it.
+    """
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas path for backend {backend!r}; pass "
+                       "interpret= explicitly")
+
+
+def pick_block(dim: int, target: int, align: int) -> int:
+    """Block size along one dim: the whole dim when it fits ``target``,
+    else the largest multiple of ``align`` that divides ``dim`` and is
+    <= ``target``, else the whole dim. A TPU block's last two dims must be
+    multiples of (8, 128) or span the array, so an unaligned divisor is
+    never picked."""
+    if dim <= target:
+        return dim
+    b = (target // align) * align
+    while b >= align:
+        if dim % b == 0:
+            return b
+        b -= align
+    return dim
+
+
 def build_plain(desc: KernelDescriptor) -> Callable:
     """Compile the descriptor as an ordinary pallas_call (no transform)."""
 
@@ -108,7 +142,7 @@ def build_plain(desc: KernelDescriptor) -> Callable:
         out_specs=[m.spec() for m in desc.out_maps],
         out_shape=list(desc.out_shape),
         scratch_shapes=list(desc.scratch_shapes),
-        interpret=desc.interpret,
+        interpret=resolve_interpret(desc.interpret),
     )
 
 

@@ -46,8 +46,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.descriptor import BlockMap, KernelDescriptor
+from repro.core.descriptor import (BlockMap, KernelDescriptor,
+                                   resolve_interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -135,7 +137,7 @@ def build_sliced(desc: KernelDescriptor, offset: int, length: int) -> Callable:
         out_shape=list(sub.out_shape),
         scratch_shapes=list(sub.scratch_shapes),
         input_output_aliases={n_in + i: i for i in range(n_out)},
-        interpret=sub.interpret,
+        interpret=resolve_interpret(sub.interpret),
     )
 
     def run(prev_outputs, *args):
@@ -149,6 +151,10 @@ def build_sliced(desc: KernelDescriptor, offset: int, length: int) -> Callable:
 # ---------------------------------------------------------------------------
 # Preemption transformation (persistent-worker form)
 # ---------------------------------------------------------------------------
+
+# VMEM of one TPU v5e TensorCore: the persistent-worker form maps whole
+# operands into it, so a larger launch is refused before compiling
+VMEM_BYTES = 128 * 2 ** 20
 
 
 def _parallel_dims(desc: KernelDescriptor) -> Tuple[int, ...]:
@@ -198,8 +204,11 @@ def make_preemptible(desc: KernelDescriptor, num_workers: int) -> Callable:
 
     def view(ref, bmap: BlockMap, pids):
         idx = bmap.index_map(*pids)
-        slices = tuple(pl.ds(b * s, s)
-                       for b, s in zip(idx, bmap.block_shape))
+        # a block spanning its whole dim is a static full slice: the
+        # compiler refuses dynamic offsets into an unaligned lane dim
+        slices = tuple(slice(None) if s == full else pl.ds(b * s, s)
+                       for b, s, full in zip(idx, bmap.block_shape,
+                                             ref.shape))
         return ref.at[slices]
 
     def kernel(start_ref, budget_ref, *refs):
@@ -242,24 +251,31 @@ def make_preemptible(desc: KernelDescriptor, num_workers: int) -> Callable:
         prog_ref[w] = done
 
     def build(arg_avals):
+        # whole operands live in VMEM for the launch (one copy each, no
+        # pipelining); the scalars and the progress vector live in SMEM
+        window = sum(_nbytes(a) for a in arg_avals) + 2 * sum(
+            _nbytes(o) for o in desc.out_shape)
+        if window > VMEM_BYTES:
+            raise ValueError(
+                f"{desc.name}: preemptible form maps whole operands into "
+                f"VMEM ({window} bytes > {VMEM_BYTES})")
+        whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
         return pl.pallas_call(
             kernel,
             grid=(W,),
-            in_specs=[pl.BlockSpec((1,), lambda w: (0,)),       # start
-                      pl.BlockSpec((1,), lambda w: (0,))]       # budget
-            + [pl.BlockSpec(s.shape, _zero_map(len(s.shape)))
-               for s in arg_avals]                               # full inputs
-            + [pl.BlockSpec(o.shape, _zero_map(len(o.shape)))
-               for o in desc.out_shape],                         # prev outputs
-            out_specs=[pl.BlockSpec(o.shape, _zero_map(len(o.shape)))
-                       for o in desc.out_shape]
-            + [pl.BlockSpec((W,), lambda w: (0,))],              # progress
+            in_specs=[smem, smem]                                # start, budget
+            + [whole] * len(arg_avals)                           # full inputs
+            + [whole] * n_out,                                   # prev outputs
+            out_specs=[whole] * n_out + [smem],                  # + progress
             out_shape=list(desc.out_shape)
             + [jax.ShapeDtypeStruct((W,), jnp.int32)],
             scratch_shapes=list(desc.scratch_shapes),
             input_output_aliases={2 + len(arg_avals) + i: i
                                   for i in range(n_out)},
-            interpret=desc.interpret,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_BYTES),
+            interpret=resolve_interpret(desc.interpret),
         )
 
     cache: dict = {}
@@ -284,5 +300,5 @@ def make_preemptible(desc: KernelDescriptor, num_workers: int) -> Callable:
     return run
 
 
-def _zero_map(ndim: int):
-    return lambda *p: (0,) * ndim
+def _nbytes(aval) -> int:
+    return int(np.prod(aval.shape)) * jnp.dtype(aval.dtype).itemsize

@@ -15,9 +15,12 @@ intercepted device code. The JAX analog implemented here:
     (transformed) Pallas kernels — sliced launches and budgeted preemptive
     launches with cooperative preemption between quanta.
 
-Because this container is CPU-only (Pallas ``interpret=True``), real-mode
-wall-times are not meaningful for policy study (that is the simulator's
-job); real mode proves FUNCTIONAL correctness end-to-end: priority
+Kernels are compiled on a TPU and interpreted on the CPU
+(``descriptor.resolve_interpret``). Every wall-clock reading waits for the
+launch's outputs (``block_until_ready``), so on the chip completion times
+and the profiler's samples are device times plus host dispatch; on the CPU
+they time the Pallas interpreter and say nothing about a device. Either
+way real mode proves functional correctness end to end: priority
 enforcement, preemption/resume with exact numerics, and the client/server
 plumbing.
 """
@@ -124,7 +127,8 @@ class RealExecutor:
     def launch_hp(self, client: Client, pk: PendingKernel) -> None:
         job: LaunchJob = pk.kernel          # type: ignore[assignment]
         t0 = time.monotonic()
-        outs = self.server.run_plain(job.desc, job.args)
+        outs = jax.block_until_ready(
+            self.server.run_plain(job.desc, job.args))
         self.hp_wall_time += time.monotonic() - t0
         job.outputs = outs
         job.complete_t = time.monotonic()
@@ -167,6 +171,7 @@ class RealExecutor:
         else:                               # default: whole kernel
             st.buffers = list(self.server.run_plain(job.desc, job.args))
             new_wm = job.desc.num_blocks
+        jax.block_until_ready(st.buffers)
         self.be_wall_time += time.monotonic() - t0
         self.scheduler.on_be_complete(client, prog, new_wm)
         if prog.remaining <= 0:
@@ -233,7 +238,7 @@ class TallyServer:
     def __init__(self, turnaround_bound: float = 0.0316e-3,
                  preempt_budget: int = 1, profile_runs: int = 1):
         self.device_attributes = {
-            "name": "pallas-interpret-cpu",
+            "name": jax.devices()[0].device_kind,
             "sm_count": 8,
             "max_threads_per_block": 1024,
         }
@@ -317,7 +322,8 @@ class TallyServer:
 
     def _measure(self, kernel, cfg: LaunchConfig) -> ExecSample:
         """Wall-clock one full execution of `kernel` (a LaunchJob) under
-        `cfg`; turnaround = quantum time per the same estimators as §4.2."""
+        `cfg`, each quantum timed to its completion; turnaround = quantum
+        time per the same estimators as §4.2."""
         job: LaunchJob = kernel
         desc, args = job.desc, job.args
         buffers = [jnp.zeros(o.shape, o.dtype) for o in desc.out_shape]
@@ -326,7 +332,8 @@ class TallyServer:
             per_slice: List[float] = []
             for off, ln in T.slice_plan(desc, cfg.param):
                 s0 = time.monotonic()
-                buffers = list(self.run_slice(desc, off, ln, buffers, args))
+                buffers = jax.block_until_ready(
+                    list(self.run_slice(desc, off, ln, buffers, args)))
                 per_slice.append(time.monotonic() - s0)
             return ExecSample(exec_time=time.monotonic() - t0,
                               turnaround=float(np.mean(per_slice)))
@@ -337,12 +344,12 @@ class TallyServer:
             while start < pre.total_tasks:
                 q0 = time.monotonic()
                 outs, _ = pre(buffers, start, self.preempt_budget, *args)
-                buffers = list(outs)
+                buffers = jax.block_until_ready(list(outs))
                 quanta.append(time.monotonic() - q0)
                 start = pre.watermark(start, self.preempt_budget)
             return ExecSample(exec_time=time.monotonic() - t0,
                               turnaround=float(np.mean(quanta)))
-        buffers = list(self.run_plain(desc, args))
+        jax.block_until_ready(self.run_plain(desc, args))
         dt = time.monotonic() - t0
         return ExecSample(exec_time=dt, turnaround=dt)
 

@@ -10,20 +10,14 @@ query heads share a KV block without materializing repeats.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.descriptor import BlockMap, KernelDescriptor
-
-
-def _pick_block(dim: int, target: int) -> int:
-    b = min(dim, target)
-    while dim % b:
-        b -= 1
-    return b
+from repro.core.descriptor import BlockMap, KernelDescriptor, pick_block
 
 
 def make_flash_body(bq: int, bk: int, T: int, D: int, causal: bool,
@@ -66,9 +60,11 @@ def make_flash_body(bq: int, bk: int, T: int, D: int, causal: bool,
 def flash_attention_desc(BH: int, S: int, T: int, D: int, group: int,
                          dtype=jnp.float32, *, causal: bool = True,
                          q_offset: int = 0, bq: int = 256, bk: int = 512,
-                         interpret: bool = True) -> KernelDescriptor:
-    bq = _pick_block(S, bq)
-    bk = _pick_block(T, bk)
+                         interpret: Optional[bool] = None) -> KernelDescriptor:
+    # q/out blocks (1, bq, D) and the in-kernel K/V windows (bk, D) slice
+    # the sublane dim, which aligns to 8
+    bq = pick_block(S, bq, 8)
+    bk = pick_block(T, bk, 8)
     grid = (BH, S // bq)
     itemsize = jnp.dtype(dtype).itemsize
     BKV = BH // group

@@ -9,6 +9,8 @@ prefill->decode handoff.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,11 +40,14 @@ def make_ssd_body(L: int, NH: int, HD: int, DS: int):
         h = h_ref[...]                                      # (NH, HD, DS)
 
         la = dtk * A[None]                                  # (L, NH)  (<0)
-        cum = jnp.cumsum(la, axis=0)
-        tot = cum[-1]                                       # (NH,)
-
         tri = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
                >= jax.lax.broadcasted_iota(jnp.int32, (L, L), 1))
+        # inclusive cumsum as a lower-triangular matmul (Mosaic has no
+        # cumsum); the chunk total is a plain sum
+        cum = jnp.dot(tri.astype(jnp.float32), la,
+                      preferred_element_type=jnp.float32)  # (L, NH)
+        tot = jnp.sum(la, axis=0)                           # (NH,)
+
         cb = ck @ bk.T                                      # (L, L)
         delta = cum[:, None] - cum[None]                    # (t, s, NH)
         delta = jnp.where(tri[..., None], delta, -jnp.inf)
@@ -65,7 +70,7 @@ def make_ssd_body(L: int, NH: int, HD: int, DS: int):
 
 def mamba2_scan_desc(B: int, S: int, NH: int, HD: int, DS: int,
                      chunk: int, dtype=jnp.float32, *,
-                     interpret: bool = True) -> KernelDescriptor:
+                     interpret: Optional[bool] = None) -> KernelDescriptor:
     L = min(chunk, S)
     while S % L:
         L -= 1

@@ -1,26 +1,18 @@
-"""Tiled matmul Pallas kernel (TPU target; interpret=True on CPU).
+"""Tiled matmul Pallas kernel (compiled on TPU, interpreted on CPU).
 
 Grid (nm, nn, nk): (m, n) parallel — the Tally-schedulable blocks — and k
 sequential (accumulation into the output tile, MXU-aligned block shapes).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.descriptor import BlockMap, KernelDescriptor
-
-
-def _pick_block(dim: int, target: int) -> int:
-    """Largest divisor of dim <= target (prefer MXU-aligned 128 multiples)."""
-    b = min(dim, target)
-    while dim % b:
-        b -= 1
-    return b
+from repro.core.descriptor import BlockMap, KernelDescriptor, pick_block
 
 
 def matmul_body(pids, a_ref, b_ref, o_ref):
@@ -36,10 +28,11 @@ def matmul_body(pids, a_ref, b_ref, o_ref):
 
 def matmul_desc(M: int, K: int, N: int, dtype=jnp.float32, *,
                 bm: int = 128, bk: int = 512, bn: int = 128,
-                interpret: bool = True) -> KernelDescriptor:
-    bm = _pick_block(M, bm)
-    bk = _pick_block(K, bk)
-    bn = _pick_block(N, bn)
+                interpret: Optional[bool] = None) -> KernelDescriptor:
+    # (bm, bk), (bk, bn), (bm, bn) blocks: rows align to 8, lanes to 128
+    bm = pick_block(M, bm, 8)
+    bk = pick_block(K, bk, 128)
+    bn = pick_block(N, bn, 128)
     grid = (M // bm, N // bn, K // bk)
     itemsize = jnp.dtype(dtype).itemsize
     return KernelDescriptor(

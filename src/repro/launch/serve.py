@@ -29,9 +29,17 @@ import numpy as np
 from repro.configs.base import all_arch_names, get_config
 from repro.core.metrics import LatencyStats
 from repro.core.traffic import maf2_like_trace
-from repro.models.transformer import build_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.transformer import TransformerLM, build_model
 from repro.serving import (BrownoutPolicy, HedgePolicy, Request,
                            RetryPolicy, ServingConfig, ServingEngine)
+
+
+def serving_model(arch: str, reduced: bool = True) -> TransformerLM:
+    """The served model: ``arch`` at its published widths, or its
+    ``.reduced()`` CPU-test preset."""
+    cfg = get_config(arch)
+    return build_model(cfg.reduced() if reduced else cfg)
 
 
 def serve(arch: str, *, requests: int = 16, capacity: int = 4,
@@ -39,12 +47,13 @@ def serve(arch: str, *, requests: int = 16, capacity: int = 4,
           colocate_train: bool = False, seed: int = 0,
           mean_rate: float = 50.0, obs=None,
           timeout: Optional[float] = None, chaos: bool = False,
-          failover: bool = False, stall_s: float = 8.0) -> dict:
-    cfg = get_config(arch).reduced()
-    model = build_model(cfg)
+          failover: bool = False, stall_s: float = 8.0,
+          reduced: bool = True) -> dict:
+    model = serving_model(arch, reduced)
+    cfg = model.cfg
     params = model.init(jax.random.PRNGKey(seed))
 
-    be_state = {"quanta": 0}
+    be_state = {"quanta": 0, "loss": None}
     be_step = None
     if colocate_train:
         from repro.configs.base import ShapeConfig
@@ -64,14 +73,16 @@ def serve(arch: str, *, requests: int = 16, capacity: int = 4,
             nonlocal be_params, be_opt
             b = {k: jnp.asarray(v)
                  for k, v in ds.batch_at(be_state["quanta"]).items()}
-            be_params, be_opt, _m = be_fn(be_params, be_opt, b)
+            be_params, be_opt, m = be_fn(be_params, be_opt, b)
             be_state["quanta"] += 1
+            be_state["loss"] = m["loss"]
 
     if chaos and timeout is None:
         # chaos without deadlines is invisible; the default budget sits
-        # above the CPU-interpret baseline p99 (queueing-dominated,
-        # seconds) but below the injected outage, so only outage victims
-        # time out
+        # above the baseline p99 of a reduced model on the host CPU
+        # (queueing-dominated, seconds; a host-side CPU timing, not a
+        # device one) but below the injected outage, so only outage
+        # victims time out
         timeout = 6.0
     retry = hedge = brownout = None
     if failover and timeout is not None:
@@ -123,6 +134,8 @@ def serve(arch: str, *, requests: int = 16, capacity: int = 4,
         "p50_ms": lat.p50() * 1e3,
         "p99_ms": lat.p99() * 1e3,
         "be_quanta": be_state["quanta"],
+        "be_loss": (None if be_state["loss"] is None
+                    else float(be_state["loss"])),
         "wall_s": time.monotonic() - t0,
     }
 
@@ -143,11 +156,15 @@ def main(argv=None) -> int:
                          "hedged requests, brownout degradation")
     ap.add_argument("--timeout", type=float, default=None,
                     help="per-request timeout in seconds")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="serve at the published widths")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = serve(args.arch, requests=args.requests, capacity=args.capacity,
                 max_new_tokens=args.max_new_tokens,
                 colocate_train=args.colocate_train, chaos=args.chaos,
-                failover=args.failover, timeout=args.timeout)
+                failover=args.failover, timeout=args.timeout,
+                reduced=args.reduced)
     print(json.dumps(out, indent=1))
     return 0
 

@@ -1,7 +1,7 @@
 """Training driver: real steps on whatever devices exist.
 
-On this CPU container it trains REDUCED configs (examples, smoke tests,
-the ~100M end-to-end run); on TPU the same driver takes the full configs.
+On the CPU it trains REDUCED configs (examples, smoke tests); on a TPU
+the same driver takes the published configs (``--full``).
 Integrates every substrate: sharded step (pjit), deterministic data
 pipeline, checkpoint/restart, heartbeats + straggler log, optional
 gradient compression, and optional Tally co-location (the training job
@@ -17,7 +17,7 @@ import dataclasses
 import json
 import time
 from pathlib import Path
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +29,7 @@ from repro.data import DataConfig, build_pipeline
 from repro.distributed.fault_tolerance import (HeartbeatMonitor,
                                                StragglerDetector)
 from repro.distributed.sharding import use_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models.transformer import build_model
@@ -40,14 +41,16 @@ def train(arch: str, *, steps: int = 100, batch: int = 8, seq: int = 128,
           ckpt_every: int = 50, resume: bool = False, seed: int = 0,
           num_microbatches: int = 1, log_every: int = 10,
           model_parallel: int = 1,
-          total_steps: Optional[int] = None) -> Dict[str, Any]:
+          total_steps: Optional[int] = None,
+          devices: Optional[Sequence] = None) -> Dict[str, Any]:
     """``total_steps`` fixes the LR-schedule horizon independently of this
     invocation's ``steps`` so a checkpoint-restart run matches a straight
-    run exactly (defaults to ``steps``)."""
+    run exactly (defaults to ``steps``). ``devices`` restricts the mesh to
+    a subset of this host's devices (default: all of them)."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
-    mesh = make_host_mesh(model_parallel)
+    mesh = make_host_mesh(model_parallel, devices)
     model = build_model(cfg)
     shape = ShapeConfig("driver", seq, batch, "train")
     horizon = total_steps or steps
@@ -75,6 +78,10 @@ def train(arch: str, *, steps: int = 100, batch: int = 8, seq: int = 128,
                     (params, opt_state))
                 start_step += 1
                 print(f"[train] resumed from step {start_step - 1}")
+        # placed as the step returns them: unplaced arrays would make the
+        # second step a cache miss and compile the step twice
+        params, opt_state = jax.device_put((params, opt_state),
+                                           bundle.in_shardings[:2])
 
         dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=seq,
                           global_batch=batch, seed=seed)
@@ -133,6 +140,7 @@ def main(argv=None) -> int:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
                 reduced=args.reduced, lr=args.lr, ckpt_dir=args.ckpt_dir,
                 ckpt_every=args.ckpt_every, resume=args.resume,

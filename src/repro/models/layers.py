@@ -4,7 +4,7 @@ Attention has two execution paths with identical math:
   - chunked online-softmax attention in pure XLA (lax.scan) — used by the
     dry-run (compiles on any backend, memory-bounded for 32k prefill), and
   - the Pallas flash kernel in ``repro.kernels`` — used when
-    ``cfg.use_pallas`` (TPU target; interpret=True in tests).
+    ``cfg.use_pallas`` (compiled on a TPU, interpreted on the CPU).
 """
 from __future__ import annotations
 

@@ -5,7 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import transforms as T
+from repro.core.descriptor import build_plain, pick_block, resolve_interpret
 from repro.kernels import ops, ref
+from repro.kernels.matmul import matmul_desc
 
 RNG = np.random.default_rng(42)
 
@@ -27,6 +30,82 @@ def test_matmul_sweep(M, K, N, dtype):
                                np.asarray(want, np.float32),
                                rtol=2e-2 if dtype == jnp.bfloat16 else 1e-4,
                                atol=2e-1 if dtype == jnp.bfloat16 else 1e-3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_matmul_multiblock(dtype):
+    """(8, 128)-aligned blocks over every grid axis: k accumulates over
+    three blocks, and m and n split."""
+    a = jnp.asarray(RNG.normal(size=(64, 384)), dtype)
+    b = jnp.asarray(RNG.normal(size=(384, 256)), dtype)
+    out = ops.matmul(a, b, bm=32, bk=128, bn=128)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref.matmul_ref(a, b), np.float32),
+                               rtol=2e-2 if dtype == jnp.bfloat16 else 1e-4,
+                               atol=5e-1 if dtype == jnp.bfloat16 else 1e-3)
+
+
+@pytest.mark.parametrize("dim,target,align,want", [
+    (3352, 128, 128, 3352),    # no 128-multiple divides it: whole dim
+    (768, 512, 128, 384),
+    (300, 256, 8, 300),        # the old picker chose 150
+    (136, 32, 8, 8),
+    (48, 128, 128, 48),        # fits the target: whole dim
+])
+def test_pick_block_never_unaligned(dim, target, align, want):
+    b = pick_block(dim, target, align)
+    assert b == want
+    assert dim % b == 0 and (b % align == 0 or b == dim)
+
+
+def test_interpret_none_resolves_from_backend():
+    """With no flag the backend decides: under JAX_PLATFORMS=cpu the
+    kernel builds and runs interpreted, and matches the oracle."""
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret(None) is True
+    desc = matmul_desc(64, 256, 256, bk=128, bn=128)
+    assert desc.interpret is None
+    a = jnp.asarray(RNG.normal(size=(64, 256)), jnp.float32)
+    b = jnp.asarray(RNG.normal(size=(256, 256)), jnp.float32)
+    out = build_plain(desc)(a, b)[0]
+    np.testing.assert_allclose(out, ref.matmul_ref(a, b), rtol=1e-4,
+                               atol=1e-3)
+
+
+@pytest.mark.parametrize("form", ["plain", "slice", "preempt"])
+@pytest.mark.parametrize("flag", [False, True])
+def test_explicit_interpret_reaches_pallas_call(form, flag):
+    """An explicit flag wins over the backend, in every launch form; read
+    off the traced ``pallas_call``, without running it."""
+    desc = matmul_desc(64, 256, 256, bk=128, bn=128, interpret=flag)
+    a = jax.ShapeDtypeStruct((64, 256), jnp.float32)
+    b = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+    o = jax.ShapeDtypeStruct((64, 256), jnp.float32)
+    if form == "plain":
+        fn, args = build_plain(desc), (a, b)
+    elif form == "slice":
+        sliced = T.build_sliced(desc, 0, 1)
+        fn, args = (lambda p, x, y: sliced([p], x, y)), (o, a, b)
+    else:
+        pre = T.make_preemptible(desc, 2)
+        fn, args = (lambda p, x, y: pre([p], 0, 1, x, y)), (o, a, b)
+    eqns = [e for e in jax.make_jaxpr(fn)(*args).eqns
+            if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1
+    assert eqns[0].params["interpret"] is flag
+
+
+def test_preemptible_refuses_operands_beyond_vmem():
+    """The persistent-worker form maps whole operands into VMEM; a launch
+    that cannot fit raises, naming the kernel and its bytes."""
+    M, K, N = 4096, 4096, 13440
+    desc = matmul_desc(M, K, N)
+    pre = T.make_preemptible(desc, 8)
+    a = jax.ShapeDtypeStruct((M, K), jnp.float32)
+    b = jax.ShapeDtypeStruct((K, N), jnp.float32)
+    o = jax.ShapeDtypeStruct((M, N), jnp.float32)
+    with pytest.raises(ValueError, match=rf"{desc.name}.*\d+ bytes"):
+        jax.eval_shape(lambda p, x, y: pre([p], 0, 1, x, y), o, a, b)
 
 
 def test_matmul_batched_lead():

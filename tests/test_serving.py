@@ -332,3 +332,23 @@ def test_brownout_shrinks_batch_and_sheds_least_slack_first(setup):
     trans = hub.registry.get("tally_serving_brownout_transitions_total")
     assert {k: c.v for k, c in trans.items()} \
         == {("enter",): 1.0, ("exit",): 1.0}
+
+
+def test_serving_model_published_widths():
+    """``serve(..., reduced=False)`` builds the published config; checked
+    from shapes alone (``jax.eval_shape``), nothing is allocated."""
+    from repro.launch.serve import serving_model
+    model = serving_model("mamba2-130m", reduced=False)
+    cfg = model.cfg
+    assert (cfg.num_layers, cfg.d_model, cfg.vocab_size) == (24, 768, 50280)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert shapes["embed"].shape == (50280, 768)
+    assert shapes["lm_head"].shape == (768, 50280)
+    ssm = shapes["layers"]["p0"]["ssm"]
+    assert ssm["wx"].shape == (24, 768, 1536)          # expand 2
+    assert ssm["wB"].shape == (24, 768, 128)           # d_state 128
+    assert ssm["wdt"].shape == (24, 768, 24)           # 24 heads of 64
+    assert ssm["out_proj"].shape == (24, 1536, 768)
+    n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    assert 167e6 < n < 169e6
+    assert serving_model("mamba2-130m").cfg.d_model == 64  # CPU preset

@@ -54,3 +54,51 @@ def test_adafactor_arch_trains():
     out = train("arctic-480b", steps=6, batch=2, seq=32, reduced=True,
                 log_every=100)
     assert np.isfinite(out["last_loss"])
+
+
+def test_train_step_compiles_once(monkeypatch):
+    """The initial state is placed as the step returns it, so later steps
+    reuse the first step's executable."""
+    steps, real_jit = [], jax.jit
+
+    def jit(fn, **kw):
+        out = real_jit(fn, **kw)
+        if "donate_argnums" in kw:              # the driver's train step
+            steps.append(out)
+        return out
+
+    monkeypatch.setattr(jax, "jit", jit)
+    train("mamba2-130m", steps=3, batch=2, seq=32, reduced=True,
+          log_every=100)
+    assert [f._cache_size() for f in steps] == [1]
+
+
+def test_compile_cache_dir(monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` is left to JAX; otherwise the cache
+    sits at the repo's fixed ``.jax_cache/``."""
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(compile_cache.REPO_ROOT / ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert (compile_cache.REPO_ROOT / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_host_mesh_on_given_devices():
+    """A mesh over a subset of the devices, with Auto axes so the steps'
+    sharding constraints apply."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(2, devices=jax.devices()[:1])
+    assert dict(mesh.shape) == {"data": 1, "model": 1}
+    assert mesh.devices.flat[0] == jax.devices()[0]
+    assert set(mesh.axis_types) == {AxisType.Auto}

@@ -41,9 +41,9 @@ def _run_preemptible(desc, args, num_workers, budgets):
 
 
 def _matmul_case():
-    a = jnp.asarray(RNG.normal(size=(96, 64)), jnp.float32)
-    b = jnp.asarray(RNG.normal(size=(64, 48)), jnp.float32)
-    desc = matmul_desc(96, 64, 48, bm=16, bk=32, bn=16)
+    a = jnp.asarray(RNG.normal(size=(96, 256)), jnp.float32)
+    b = jnp.asarray(RNG.normal(size=(256, 384)), jnp.float32)
+    desc = matmul_desc(96, 256, 384, bm=16, bk=128, bn=128)   # grid (6,3,2)
     want = [ref.matmul_ref(a, b)]
     return desc, (a, b), want
 
@@ -132,9 +132,9 @@ def test_watermark_monotone_and_bounded(num_workers, budget, start_frac):
 def test_preemptible_matmul_property(seed, num_workers, budget):
     """Any (W, budget) schedule completes and matches the oracle."""
     r = np.random.default_rng(seed)
-    a = jnp.asarray(r.normal(size=(32, 16)), jnp.float32)
-    b = jnp.asarray(r.normal(size=(16, 32)), jnp.float32)
-    desc = matmul_desc(32, 16, 32, bm=8, bk=8, bn=8)
+    a = jnp.asarray(r.normal(size=(32, 256)), jnp.float32)
+    b = jnp.asarray(r.normal(size=(256, 512)), jnp.float32)
+    desc = matmul_desc(32, 256, 512, bm=8, bk=128, bn=128)    # grid (4,4,2)
     outs = _run_preemptible(desc, (a, b), num_workers, [budget])
     np.testing.assert_allclose(np.asarray(outs[0]),
                                np.asarray(ref.matmul_ref(a, b)),

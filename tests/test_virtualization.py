@@ -18,18 +18,19 @@ def server():
     return TallyServer()
 
 
-def _mm_case(m=96, k=64, n=48):
+def _mm_case(m=96, k=256, n=384):
+    # blocks (16, 128) x (128, 128): lane dims aligned to 128 as on a TPU
     a = jnp.asarray(RNG.normal(size=(m, k)), jnp.float32)
     b = jnp.asarray(RNG.normal(size=(k, n)), jnp.float32)
-    return matmul_desc(m, k, n, bm=16, bk=32, bn=16), (a, b), \
+    return matmul_desc(m, k, n, bm=16, bk=128, bn=128), (a, b), \
         ref.matmul_ref(a, b)
 
 
 def test_priority_and_numerics(server):
     hp = server.register("hp", priority=0)
     be = server.register("be", priority=1)
-    d_be, args_be, want_be = _mm_case(96, 64, 48)
-    d_hp, args_hp, want_hp = _mm_case(32, 64, 48)
+    d_be, args_be, want_be = _mm_case(96, 256, 384)
+    d_hp, args_hp, want_hp = _mm_case(32, 256, 384)
     job_be = be.launch(d_be, *args_be)
     job_hp = hp.launch(d_hp, *args_hp)
     server.serve_until_idle(max_seconds=180)
@@ -42,7 +43,7 @@ def test_priority_and_numerics(server):
 
 def test_be_kernel_is_transformed(server):
     be = server.register("be", priority=1)
-    desc, args, want = _mm_case(96, 64, 48)
+    desc, args, want = _mm_case(96, 256, 384)
     job = be.launch(desc, *args)
     server.serve_until_idle(max_seconds=180)
     np.testing.assert_allclose(job.result(0)[0], want, rtol=5e-4,
@@ -77,7 +78,7 @@ def test_client_side_state_caching(server):
 
 def test_hp_runs_untransformed(server):
     hp = server.register("hp", priority=0)
-    desc, args, want = _mm_case(48, 64, 32)
+    desc, args, want = _mm_case(48, 256, 256)
     job = hp.launch(desc, *args)
     server.serve_until_idle(max_seconds=180)
     np.testing.assert_allclose(job.result(0)[0], want, rtol=5e-4,
