@@ -126,6 +126,17 @@ class ServingProbe:
             "tally_serving_ttft_seconds",
             "wall-clock time to first token",
             buckets=DEFAULT_BUCKETS).child()
+        self.queue_wait = r.histogram(
+            "tally_serving_queue_wait_seconds",
+            "wall-clock wait in the queue, submit to admission",
+            buckets=DEFAULT_BUCKETS).child()
+        # decode batch: slots_total's rate over steps_total's is its mean
+        self.decode_steps = r.counter(
+            "tally_serving_decode_steps_total", "decode steps").child()
+        self.decode_slots = r.counter(
+            "tally_serving_decode_slots_total",
+            "slot-steps decoded (active slots summed over decode steps)"
+        ).child()
         self.quanta = r.counter(
             "tally_serving_be_quanta_total",
             "opportunistic best-effort training quanta granted").child()
@@ -146,8 +157,13 @@ class ServingProbe:
             "tally_serving_brownout_transitions_total",
             "brownout mode enter/exit transitions", ("state",))
 
-    def admitted(self, ttft: float) -> None:
+    def admitted(self, ttft: float, queue_wait: float) -> None:
         self.ttft.observe(ttft)
+        self.queue_wait.observe(queue_wait)
+
+    def decoded(self, active: int) -> None:
+        self.decode_steps.v += 1.0
+        self.decode_slots.v += active
 
     def retired(self, latency: float) -> None:
         self.requests.v += 1.0

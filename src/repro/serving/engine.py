@@ -31,6 +31,14 @@ Request-level robustness (PR 9), all opt-in:
     effective decode batch and sheds the lowest-deadline-slack queued
     requests (the ones least likely to make their cutoff) until pressure
     clears — with hysteresis so the engine doesn't flap.
+
+Phase spans: ``step`` marks its phases with ``jax.profiler.TraceAnnotation``
+(``tally.serve.step``; per admission ``tally.serve.admit`` holding
+``prefill``, ``insert`` and ``first_token``; then ``decode``,
+``decode_wait``, ``emit`` or ``be_quantum``), so a profiler trace puts
+every idle stretch of the device under the engine phase the host was in.
+The spans cost well under a microsecond each when no trace is active;
+their stats are built only while one is.
 """
 from __future__ import annotations
 
@@ -49,6 +57,8 @@ from repro.configs.base import ModelConfig
 from repro.core.metrics import percentile
 from repro.models.transformer import TransformerLM, pad_cache
 
+_Span = jax.profiler.TraceAnnotation
+
 
 @dataclass
 class Request:
@@ -57,6 +67,7 @@ class Request:
     max_new_tokens: int = 16
     eos_id: Optional[int] = None
     submit_t: float = field(default_factory=time.monotonic)
+    admit_t: Optional[float] = None       # picked from the queue to prefill
     first_token_t: Optional[float] = None
     done_t: Optional[float] = None
     tokens: List[int] = field(default_factory=list)
@@ -70,6 +81,11 @@ class Request:
     @property
     def done(self) -> bool:
         return self.done_t is not None
+
+    @property
+    def queue_wait(self) -> Optional[float]:
+        return (self.admit_t - self.submit_t
+                if self.admit_t is not None else None)
 
     @property
     def ttft(self) -> Optional[float]:
@@ -280,18 +296,26 @@ class ServingEngine:
             return False                  # every queued request backoff-gated
         req = min(ready, key=lambda r: self._slack_key(r, now))
         self.queue.remove(req)
-        toks = jnp.asarray(req.prompt[None, :])
-        logits, cache = self._prefill(self.params, toks)
-        self._insert_slot(slot, cache)
-        first = int(jnp.argmax(logits[0, -1]))
-        req.tokens.append(first)
-        req.first_token_t = self._clock()
-        if self.obs is not None:
-            self.obs.admitted(req.ttft)
-        self._slot_req[slot] = req
-        self._lengths[slot] = len(req.prompt)
-        self._next_tok[slot] = first
-        self._active[slot] = True
+        req.admit_t = now
+        rid = {"rid": req.rid} if _Span.is_enabled() else {}
+        stats = (dict(rid, slot=slot, prompt_len=len(req.prompt),
+                      wait_us=1e6 * req.queue_wait) if rid else {})
+        with _Span("tally.serve.admit", **stats):
+            with _Span("tally.serve.prefill", **rid):
+                toks = jnp.asarray(req.prompt[None, :])
+                logits, cache = self._prefill(self.params, toks)
+            with _Span("tally.serve.insert", **rid):
+                self._insert_slot(slot, cache)
+            with _Span("tally.serve.first_token", **rid):
+                first = int(jnp.argmax(logits[0, -1]))
+            req.tokens.append(first)
+            req.first_token_t = self._clock()
+            if self.obs is not None:
+                self.obs.admitted(req.ttft, req.queue_wait)
+            self._slot_req[slot] = req
+            self._lengths[slot] = len(req.prompt)
+            self._next_tok[slot] = first
+            self._active[slot] = True
         return True
 
     def _free_slot(self, slot: int) -> None:
@@ -341,6 +365,7 @@ class ServingEngine:
             primary = group["primary"]
             # the hedge won: its output lands on the caller's handle
             primary.tokens = list(req.tokens)
+            primary.admit_t = req.admit_t
             primary.first_token_t = req.first_token_t
             req.done_t = now
             primary.done_t = now
@@ -374,6 +399,7 @@ class ServingEngine:
             return False
         req.attempt += 1
         req.tokens = []
+        req.admit_t = None
         req.first_token_t = None
         req.eligible_t = now + rp.backoff(req.rid, req.attempt)
         req.deadline = req.eligible_t + req.timeout
@@ -476,46 +502,60 @@ class ServingEngine:
 
     def step(self) -> bool:
         """One engine iteration. Returns True if any work was done."""
-        shed = self._shed_expired() > 0
-        changed = self._brownout_tick()
-        changed = self._spawn_hedges() or changed
-        # admit as many as possible (priority: serving work first)
-        admitted = False
-        while self._admit():
-            admitted = True
-        if not self._active.any():
-            if admitted or shed or changed:
-                return True
-            if self.be_hook is not None:
-                # opportunistic best-effort quantum (Fig. 4 policy at the
-                # engine level): only when the HP engine is fully idle
-                self.be_hook()
-                self.be_quanta += 1
-                if self.obs is not None:
-                    self.obs.be_quantum()
-                return True
-            return False
-        tokens = jnp.asarray(self._next_tok[:, None])
-        lengths = jnp.asarray(self._lengths)
-        next_tok, self.cache = self._decode(self.params, tokens,
-                                            self.cache, lengths)
-        next_np = np.asarray(next_tok)
-        for slot in np.flatnonzero(self._active):
-            req = self._slot_req[slot]
-            if req is None:
-                continue    # freed mid-loop by a hedge first-wins cancel
-            tok = int(next_np[slot])
-            req.tokens.append(tok)
-            self._lengths[slot] += 1
-            self._next_tok[slot] = tok
-            hit_eos = req.eos_id is not None and tok == req.eos_id
-            out_of_room = self._lengths[slot] + 1 >= self.scfg.max_len
-            if (len(req.tokens) >= req.max_new_tokens or hit_eos
-                    or out_of_room):
-                self._retire(slot)
+        with _Span("tally.serve.step"):
+            shed = self._shed_expired() > 0
+            changed = self._brownout_tick()
+            changed = self._spawn_hedges() or changed
+            # admit as many as possible (priority: serving work first)
+            admitted = False
+            while self._admit():
+                admitted = True
+            if not self._active.any():
+                if admitted or shed or changed:
+                    return True
+                if self.be_hook is not None:
+                    # opportunistic best-effort quantum (Fig. 4 policy at
+                    # the engine level): only when the HP engine is idle
+                    with _Span("tally.serve.be_quantum"):
+                        self.be_hook()
+                    self.be_quanta += 1
+                    if self.obs is not None:
+                        self.obs.be_quantum()
+                    return True
+                return False
+            self._decode_active()
+            return True
+
+    def _decode_active(self) -> None:
+        """One decode step over the active slots, then their new tokens."""
+        active = np.flatnonzero(self._active)
+        stats = ({"active": len(active),
+                  "kv_tokens": int(self._lengths[active].sum())}
+                 if _Span.is_enabled() else {})
+        with _Span("tally.serve.decode", **stats):
+            tokens = jnp.asarray(self._next_tok[:, None])
+            lengths = jnp.asarray(self._lengths)
+            next_tok, self.cache = self._decode(self.params, tokens,
+                                                self.cache, lengths)
+        with _Span("tally.serve.decode_wait"):
+            next_np = np.asarray(next_tok)
+        with _Span("tally.serve.emit"):
+            for slot in active:
+                req = self._slot_req[slot]
+                if req is None:
+                    continue    # freed mid-loop by a hedge first-wins cancel
+                tok = int(next_np[slot])
+                req.tokens.append(tok)
+                self._lengths[slot] += 1
+                self._next_tok[slot] = tok
+                hit_eos = req.eos_id is not None and tok == req.eos_id
+                out_of_room = self._lengths[slot] + 1 >= self.scfg.max_len
+                if (len(req.tokens) >= req.max_new_tokens or hit_eos
+                        or out_of_room):
+                    self._retire(slot)
         if self.obs is not None:
+            self.obs.decoded(len(active))
             self.obs.slots(float(self._active.sum()))
-        return True
 
     def run_until_idle(self, max_steps: int = 10_000) -> None:
         for _ in range(max_steps):

@@ -131,7 +131,13 @@ def test_quantile_cross_check_property(xs):
         wq.add(x)
     exact = float(np.quantile(np.asarray(xs), 0.9))
     assert abs(wq.value() - exact) < 1e-9
-    assert abs(h.quantile(0.9) - exact) <= 0.05 + 1e-9
+    # the histogram interpolates inside the bucket holding rank q * n, so
+    # its reference is the order statistic of that rank: np.quantile
+    # interpolates between order statistics, which on sparse data (28
+    # zeros and 4 ones) can lie in another bucket
+    rank = math.ceil(0.9 * len(xs))
+    order_stat = float(np.sort(np.asarray(xs))[rank - 1])
+    assert abs(h.quantile(0.9) - order_stat) <= 0.05 + 1e-9
 
 
 # ---------------------------------------------------------------------------
@@ -310,13 +316,18 @@ def test_registry_matches_engine_counts():
 def test_serving_probe_registers_and_observes():
     hub = ObsHub()
     p = ServingProbe(hub)
-    p.admitted(0.01)
+    p.admitted(0.01, 0.002)
     p.retired(0.05)
     p.be_quantum()
     p.slots(2.0)
-    assert hub.registry.get("tally_serving_requests_total").child().value \
-        == 1.0
-    assert hub.registry.get("tally_serving_ttft_seconds").child().count == 1
+    p.decoded(3)
+    p.decoded(2)
+    r = hub.registry
+    assert r.get("tally_serving_requests_total").child().value == 1.0
+    assert r.get("tally_serving_ttft_seconds").child().count == 1
+    assert r.get("tally_serving_queue_wait_seconds").child().sum == 0.002
+    assert r.get("tally_serving_decode_steps_total").child().value == 2.0
+    assert r.get("tally_serving_decode_slots_total").child().value == 5.0
     assert hub.serving() is hub.serving()      # memoized
 
 

@@ -352,3 +352,197 @@ def test_serving_model_published_widths():
     n = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
     assert 167e6 < n < 169e6
     assert serving_model("mamba2-130m").cfg.d_model == 64  # CPU preset
+
+
+# ---------------------------------------------------------------------------
+# Phase spans, request stamps and probe counters
+# ---------------------------------------------------------------------------
+
+
+def _serve_spans(trace_dir):
+    """The engine's ``tally.serve.*`` host spans in a profiler trace:
+    (name, start_ns, end_ns, stats), by start."""
+    from jax.profiler import ProfileData
+    path = sorted(trace_dir.glob("**/*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)) for e in line.events
+                        if e.name.startswith("tally.serve.")]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _spy_decode(eng):
+    """Record (active slots, their cached tokens) at each decode call."""
+    calls, decode = [], eng._decode
+
+    def spy(params, tokens, cache, lengths):
+        act = np.flatnonzero(eng._active)
+        calls.append((len(act), int(eng._lengths[act].sum())))
+        return decode(params, tokens, cache, lengths)
+    eng._decode = spy
+    return calls
+
+
+PROMPTS = ((5, 3), (6, 4), (4, 2))      # (prompt length, max_new_tokens)
+
+
+@pytest.fixture(scope="module")
+def traced(setup, tmp_path_factory):
+    cfg, model, params = setup
+    eng = ServingEngine(model, params, ServingConfig(capacity=2, max_len=48),
+                        best_effort_hook=lambda: None)
+    calls = _spy_decode(eng)
+    reqs = [eng.submit(np.arange(n, dtype=np.int32), max_new_tokens=k)
+            for n, k in PROMPTS]
+    d = tmp_path_factory.mktemp("trace")
+    jax.profiler.start_trace(str(d))
+    try:
+        eng.run_until_idle()
+        eng.step()                       # idle: one best-effort quantum
+    finally:
+        jax.profiler.stop_trace()
+    return reqs, calls, _serve_spans(d)
+
+
+def test_one_admit_span_per_admitted_request(traced):
+    reqs, _, spans = traced
+    admits = [st for n, _, _, st in spans if n == "tally.serve.admit"]
+    assert sorted(a["rid"] for a in admits) == [r.rid for r in reqs]
+    for a in admits:
+        r = reqs[a["rid"]]
+        assert a["prompt_len"] == len(r.prompt)
+        assert a["wait_us"] == pytest.approx(1e6 * r.queue_wait)
+
+
+def test_decode_span_stats_match_slot_state(traced):
+    _, calls, spans = traced
+    got = [(st["active"], st["kv_tokens"]) for n, _, _, st in spans
+           if n == "tally.serve.decode"]
+    assert got == calls
+    assert len(calls) == 3               # 2 slots: steps of 2, 2, then 1
+    assert sum(a for a, _ in calls) == sum(k - 1 for _, k in PROMPTS)
+
+
+def test_admission_phases_nest_inside_admit(traced):
+    _, _, spans = traced
+    admits = [s for s in spans if s[0] == "tally.serve.admit"]
+    steps = [s for s in spans if s[0] == "tally.serve.step"]
+    for _, s0, e0, st in admits:
+        inner = [(n, s, e) for n, s, e, x in spans
+                 if x.get("rid") == st["rid"] and n != "tally.serve.admit"]
+        assert [n for n, _, _ in inner] == [
+            "tally.serve.prefill", "tally.serve.insert",
+            "tally.serve.first_token"]
+        assert all(s0 <= s and e <= e0 for _, s, e in inner)
+        assert any(s <= s0 and e0 <= e for _, s, e, _ in steps)
+    for name in ("tally.serve.decode", "tally.serve.decode_wait",
+                 "tally.serve.emit", "tally.serve.be_quantum"):
+        phase = [s for s in spans if s[0] == name]
+        assert phase and all(any(s <= p[1] and p[2] <= e
+                                 for _, s, e, _ in steps) for p in phase)
+
+
+def test_queue_wait_stamp_resets_on_retry(setup):
+    cfg, model, params = setup
+    clk = _FakeClock()
+    eng = ServingEngine(model, params, ServingConfig(capacity=1, max_len=48),
+                        clock=clk, retry=RetryPolicy(max_retries=2,
+                                                     backoff_base=1.0,
+                                                     jitter=0.0))
+    r = eng.submit(np.arange(4, dtype=np.int32), max_new_tokens=20,
+                   timeout=2.0)
+    assert r.admit_t is None and r.queue_wait is None
+    clk.t = 0.25
+    eng.step()
+    assert r.admit_t == 0.25 and r.queue_wait == 0.25
+    clk.t = 3.0                          # stuck in its slot -> retry #1
+    eng.step()
+    assert r.attempt == 1 and r in eng.queue
+    assert r.admit_t is None and r.queue_wait is None
+    clk.t = 4.5                          # backoff gate (4.0) open
+    eng.run_until_idle()
+    assert r.done and not r.shed
+    assert r.admit_t == 4.5
+    assert r.queue_wait == r.admit_t - r.submit_t == 4.5
+
+
+def test_probe_counts_decode_steps_slots_and_admissions(setup):
+    from repro.obs import ObsHub
+
+    cfg, model, params = setup
+    hub = ObsHub()
+    eng = ServingEngine(model, params, ServingConfig(capacity=2, max_len=48),
+                        obs=hub)
+    calls = _spy_decode(eng)
+    reqs = [eng.submit(np.arange(n, dtype=np.int32), max_new_tokens=k)
+            for n, k in PROMPTS]
+    eng.run_until_idle()
+    r = hub.registry
+    assert r.get("tally_serving_decode_steps_total").child().value == \
+        len(calls)
+    assert r.get("tally_serving_decode_slots_total").child().value == \
+        sum(a for a, _ in calls) == sum(k - 1 for _, k in PROMPTS)
+    waits = r.get("tally_serving_queue_wait_seconds").child()
+    assert waits.count == len(reqs)
+    assert waits.sum == pytest.approx(sum(q.queue_wait for q in reqs))
+
+
+def _plain_serving(model, params, prompts, n_new, capacity, max_len):
+    """The engine's algorithm with nothing around it: FIFO admission into
+    the lowest free slot, a B=1 prefill written into the slot cache, one
+    greedy decode step over every slot per iteration."""
+    from repro.configs.base import kv_cache_specs
+    from repro.models.transformer import pad_cache
+
+    specs = kv_cache_specs(model.cfg, capacity, max_len)
+    cache = {k: jnp.zeros(s.shape, s.dtype) for k, s in specs.items()}
+    prefill = jax.jit(model.prefill)
+
+    def impl(params, tokens, cache, lengths):
+        logits, cache = model.decode_step(params, tokens, cache, lengths)
+        return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), cache
+    decode = jax.jit(impl)
+    queue, out = list(range(len(prompts))), [[] for _ in prompts]
+    slot_of = [None] * capacity
+    lengths = np.zeros(capacity, np.int32)
+    nxt = np.zeros(capacity, np.int32)
+    while queue or any(i is not None for i in slot_of):
+        while queue and None in slot_of:
+            slot, i = slot_of.index(None), queue.pop(0)
+            logits, c = prefill(params, jnp.asarray(prompts[i][None, :]))
+            for k, a in pad_cache(c, max_len).items():
+                cache[k] = jax.lax.dynamic_update_slice(
+                    cache[k], a.astype(cache[k].dtype),
+                    (0, slot) + (0,) * (a.ndim - 2))
+            out[i].append(int(jnp.argmax(logits[0, -1])))
+            slot_of[slot] = i
+            lengths[slot], nxt[slot] = len(prompts[i]), out[i][-1]
+        tok, cache = decode(params, jnp.asarray(nxt[:, None]), cache,
+                            jnp.asarray(lengths))
+        tok = np.asarray(tok)
+        for slot, i in enumerate(slot_of):
+            if i is None:
+                continue
+            out[i].append(int(tok[slot]))
+            lengths[slot] += 1
+            nxt[slot] = tok[slot]
+            if len(out[i]) >= n_new[i] or lengths[slot] + 1 >= max_len:
+                slot_of[slot], lengths[slot] = None, 0
+    return out
+
+
+def test_tokens_unchanged_with_spans_off(setup):
+    cfg, model, params = setup
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n, _ in PROMPTS]
+    n_new = [k + 2 for _, k in PROMPTS]
+    eng = ServingEngine(model, params, ServingConfig(capacity=2, max_len=48))
+    reqs = [eng.submit(p, max_new_tokens=k) for p, k in zip(prompts, n_new)]
+    eng.run_until_idle()
+    assert [r.tokens for r in reqs] == _plain_serving(
+        model, params, prompts, n_new, capacity=2, max_len=48)
