@@ -159,7 +159,8 @@ def served_logits(reduced: bool = False) -> dict:
 
     served = [[] for _ in seqs]
     for slot, seq in enumerate(seqs):      # admit: prefill into its slot
-        logits, cache = engine._prefill(params, jnp.asarray([seq], jnp.int32))
+        logits, cache = engine._prefill(engine.params,
+                                        jnp.asarray([seq], jnp.int32))
         engine._insert_slot(slot, cache)
         served[slot].append(logits[0, -1])
     decode = jax.jit(model.decode_step)
@@ -168,7 +169,7 @@ def served_logits(reduced: bool = False) -> dict:
         for slot, seq in enumerate(seqs):
             seq.append(int(jnp.argmax(served[slot][-1])))
         tokens = jnp.asarray([[seq[-1]] for seq in seqs], jnp.int32)
-        logits, engine.cache = decode(params, tokens, engine.cache,
+        logits, engine.cache = decode(engine.params, tokens, engine.cache,
                                       jnp.asarray(lengths))
         lengths += 1
         for slot in range(len(seqs)):

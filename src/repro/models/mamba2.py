@@ -26,6 +26,11 @@ from repro.distributed.sharding import constrain
 from repro.models.common import P
 
 
+# Leaves read in float32 (the recurrence's A, D and dt bias, the gated
+# norm's scale); every other leaf is read through ``.astype(x.dtype)``.
+F32_PARAMS = frozenset({"A_log", "D", "dt_bias", "norm"})
+
+
 def mamba2_specs(cfg) -> Dict[str, P]:
     d = cfg.d_model
     s = cfg.ssm

@@ -73,6 +73,13 @@ def _mlp_specs(cfg: ModelConfig) -> Dict[str, P]:
     }
 
 
+# Leaves the model reads in float32: the RMS-norm scales (``rms_norm``
+# casts its scale to f32) and the SSM's own. Every other leaf is read
+# only through ``.astype(cfg.dtype)``, so ``serving_params`` casts it once.
+F32_PARAMS = frozenset({"ln1", "ln2", "ln_x", "final_norm", "norm"}
+                       ) | m2.F32_PARAMS
+
+
 class TransformerLM:
     """Model object: specs + pure forward fns (train / prefill / decode)."""
 
@@ -147,6 +154,21 @@ class TransformerLM:
 
     def param_axes(self):
         return axes_from_specs(self.specs())
+
+    def serving_params(self, params):
+        """The parameter tree as it is served: each leaf outside
+        ``F32_PARAMS`` cast to ``cfg.dtype`` once, the rest as they are.
+        Prefill and decode then compute with the same bits as from
+        ``params``, without writing a cast copy of every weight per call.
+        Leaves already in ``cfg.dtype`` (f32 compute) come back as they
+        are."""
+        dt = jnp.dtype(self.cfg.dtype)
+
+        def serve(path, x):
+            if path[-1].key in F32_PARAMS or x.dtype == dt:
+                return x
+            return x.astype(dt)
+        return jax.tree_util.tree_map_with_path(serve, params)
 
     # -- encoder (audio) ------------------------------------------------------
 

@@ -156,6 +156,17 @@ class ServingProbe:
         self.brownouts = r.counter(
             "tally_serving_brownout_transitions_total",
             "brownout mode enter/exit transitions", ("state",))
+        self.weight_bytes = r.gauge(
+            "tally_serving_weight_bytes",
+            "bytes of served weights by dtype", ("dtype",))
+
+    def weights(self, leaves) -> None:
+        """Set once, from the arrays of the served parameter tree."""
+        nbytes: Dict[str, int] = {}
+        for x in leaves:
+            nbytes[str(x.dtype)] = nbytes.get(str(x.dtype), 0) + x.nbytes
+        for dtype, n in nbytes.items():
+            self.weight_bytes.child(dtype).set(float(n))
 
     def admitted(self, ttft: float, queue_wait: float) -> None:
         self.ttft.observe(ttft)

@@ -193,7 +193,9 @@ class ServingEngine:
                  hedge: Optional[HedgePolicy] = None,
                  brownout: Optional[BrownoutPolicy] = None):
         self.model = model
-        self.params = params
+        # held in the compute dtype: prefill and decode read each weight
+        # once per call instead of casting a copy of it on every call
+        self.params = model.serving_params(params)
         self.scfg = scfg
         self.cfg = model.cfg
         self.queue: Deque[Request] = deque()
@@ -217,6 +219,8 @@ class ServingEngine:
         if obs is not None and hasattr(obs, "serving"):
             obs = obs.serving()
         self.obs = obs
+        if obs is not None:
+            obs.weights(jax.tree.leaves(self.params))
         cap, T = scfg.capacity, scfg.max_len
         self._lengths = np.zeros(cap, np.int32)        # tokens in cache
         self._active = np.zeros(cap, bool)

@@ -331,6 +331,31 @@ def test_serving_probe_registers_and_observes():
     assert hub.serving() is hub.serving()      # memoized
 
 
+def test_serving_weight_bytes_gauge_counts_the_served_tree():
+    import jax
+
+    from repro.configs import get_config
+    from repro.models.transformer import build_model
+    from repro.serving import ServingConfig, ServingEngine
+
+    model = build_model(get_config("mamba2-130m").reduced())
+    params = model.init(jax.random.PRNGKey(0))
+    want = {}
+    for x in jax.tree.leaves(model.serving_params(params)):
+        want[str(x.dtype)] = want.get(str(x.dtype), 0) + x.nbytes
+    assert sorted(want) == ["bfloat16", "float32"]
+
+    hub = ObsHub()
+    eng = ServingEngine(model, params, ServingConfig(capacity=1, max_len=16),
+                        obs=hub)
+    fam = hub.registry.get("tally_serving_weight_bytes")
+    assert {k[0]: c.value for k, c in fam.items()} == want
+    assert sum(x.nbytes for x in jax.tree.leaves(eng.params)) == \
+        sum(want.values())
+    bare = ServingEngine(model, params, ServingConfig(capacity=1, max_len=16))
+    assert bare.obs is None
+
+
 def test_dashboard_renders_from_small_fleet_run():
     from repro.core.fleet import FleetSimulator, be_job, hp_service
 

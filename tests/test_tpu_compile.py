@@ -1,5 +1,6 @@
 """Compile rehearsal: the main path's Pallas kernels, compiled for one
-described TPU v5e chip with ``interpret=False`` at mamba2-130m's shapes.
+described TPU v5e chip with ``interpret=False`` at mamba2-130m's shapes,
+and the serving engine's decode at mamba2-130m's published widths.
 
 Nothing runs: the TPU compiler is installed, and it compiles for a chip
 that is described and not attached, raising what the chip's compiler
@@ -7,7 +8,10 @@ would raise (unaligned blocks, VMEM overflows, unsupported primitives).
 The topology is described inside a fixture, never at import, because
 only one process at a time may load the TPU library.
 """
+import dataclasses
 import os
+import re
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -15,11 +19,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 from jaxlib.mlir.ir import MLIRError
 
+from repro.configs import get_config, kv_cache_specs
 from repro.core import transforms as T
 from repro.core.descriptor import build_plain
 from repro.kernels.flash_attention import flash_attention_desc
 from repro.kernels.mamba2_scan import mamba2_scan_desc
 from repro.kernels.matmul import matmul_desc
+from repro.models.transformer import build_model
+from repro.serving import ServingEngine
 
 # mamba2-130m, 64 tokens: out_proj (d_inner 1536 -> d_model 768) and
 # in_proj (768 -> z, x, B, C, dt = 2*1536 + 2*128 + 24 = 3352); bm=8 gives
@@ -113,3 +120,39 @@ def test_ssd_scan_compiles(one_chip):
     text = _compile_text(build_plain(desc), sds(B, S, NH, HD), sds(B, S, NH),
                          sds(NH), sds(B, S, DS), sds(B, S, DS), sds(NH))
     assert "tpu_custom_call" in text
+
+
+def test_served_decode_reads_weights_once(one_chip):
+    """The engine's decode over 16 slots of 2048 at mamba2-130m's published
+    widths (tied embedding, vocabulary padded to 50288): fed the served
+    tree it moves under half the bytes it moves fed the float32 tree, and
+    no convert writes a bf16 copy of a layer-stacked weight per call."""
+    cfg = dataclasses.replace(get_config("mamba2-130m"), tie_embeddings=True,
+                              vocab_size=50288)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    shapes = model.param_shapes()
+    cache = on_chip(kv_cache_specs(cfg, 16, 2048))
+    tokens = jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    decode = jax.jit(lambda *a: ServingEngine._decode_impl(
+        SimpleNamespace(model=model), *a))
+    stacked_cast = re.compile(
+        rf"= bf16\[{cfg.num_layers},[0-9,]+\]\S* convert\(")
+    moved, casts = {}, {}
+    for form, params in (
+            ("f32", shapes),
+            ("served", jax.eval_shape(model.serving_params, shapes))):
+        compiled = decode.lower(on_chip(params), tokens, cache,
+                                lengths).compile()
+        cost = compiled.cost_analysis()
+        moved[form] = (cost[0] if isinstance(cost, list) else cost
+                       )["bytes accessed"]
+        entry = compiled.as_text().split("\nENTRY", 1)[1].split("\n}", 1)[0]
+        casts[form] = len(stacked_cast.findall(entry))
+    assert moved["served"] < 0.5 * moved["f32"], moved
+    assert casts["f32"] > 0 and casts["served"] == 0, casts
