@@ -31,13 +31,6 @@ class Record:
 
 
 @dataclass
-class DecodeCall:
-    end: float                      # host clock when step() returned
-    active: int                     # slots decoded
-    kv_tokens: int                  # tokens held by those slots' caches
-
-
-@dataclass
 class Stall:
     start: float                    # host clock
     seconds: float
@@ -51,7 +44,6 @@ class Window:
     seconds: float
     end_drain: float = 0.0
     records: List[Record] = field(default_factory=list)
-    decodes: List[DecodeCall] = field(default_factory=list)
     compiles_in_window: int = 0
     longest_step: float = 0.0       # seconds of the longest engine.step()
     longest_step_admitted: int = 0  # requests it admitted
@@ -92,21 +84,16 @@ def run_window(engine, arrivals, prompts, seconds: float, drain_s: float,
                 worked = engine.step()
             t = time.monotonic()
             step_s = t - now
-            added = admitted = kv = 0
+            admitted = 0
             for rec, b in zip(open_recs, before):
                 k = len(rec.req.tokens) - b
                 if k <= 0:
                     continue
-                added += k
                 if b == 0:
                     admitted += 1
                     rec.times.append(rec.req.first_token_t)
                     k -= 1
                 rec.times.extend([t] * k)
-                if k:
-                    kv += len(rec.prompt) + len(rec.req.tokens) - 1
-            if added > admitted:
-                w.decodes.append(DecodeCall(t, added - admitted, kv))
             if step_s > w.longest_step:
                 w.longest_step, w.longest_step_admitted = step_s, admitted
             if step_s > STALL_S:

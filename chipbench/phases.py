@@ -41,20 +41,7 @@ PREFIX = "tally.serve."
 STEP = PREFIX + "step"
 DECODE = PREFIX + "decode"
 STAMP_METRICS = ("hp_admit_ms", "hp_queue_wait_ms")   # read in each window
-Span = Tuple[str, float, float, dict]      # name, start_ns, duration_ns, stats
-
-
-def load_program_spans(path: str) -> List[Span]:
-    """The host events named ``tally.`` in an ``.xplane.pb``, with stats."""
-    from jax.profiler import ProfileData
-    out: List[Span] = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                out += [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
-                        for e in line.events if e.name.startswith("tally.")]
-    out.sort(key=lambda s: s[1])
-    return out
+Span = trace_reduce.ProgramSpan            # name, start_ns, duration_ns, stats
 
 
 def busy_intervals(trace: dict) -> List[List[Tuple[float, float]]]:
@@ -95,6 +82,11 @@ def idle_inside(trace: dict, spans: List[Span], name: str,
     return out
 
 
+def decode_stats(spans: List[Span]) -> List[dict]:
+    """The stats of each ``decode`` span, one mapping per decode step."""
+    return [stats for n, _, _, stats in spans if n == DECODE]
+
+
 def decoding_steps(spans: List[Span]) -> List[Span]:
     """The ``step`` spans that hold a ``decode`` span."""
     decodes = sorted(s for n, s, _, _ in spans if n == DECODE)
@@ -127,15 +119,6 @@ def idle_by_phase(trace: dict, spans: List[Span],
     window; ``step`` and ``admit`` hold the leaves beside them."""
     names = sorted({n for n, *_ in spans if n.startswith(PREFIX)})
     return {n: sum(idle_inside(trace, spans, n, window)) for n in names}
-
-
-def labelled_gaps(trace: dict, spans: List[Span], top: int = 10) -> list:
-    """``reduce``'s longest gaps, each named by the innermost benchmark or
-    engine span around its middle (the engine's, of two equally long)."""
-    merged = dict(trace, spans=[(n, s, d) for n, s, d, _ in spans]
-                  + trace["spans"])
-    return trace_reduce.reduce(merged, trace_reduce.window_of(trace),
-                               top=top)["gaps"]
 
 
 class StepClock:
@@ -213,9 +196,9 @@ def main(argv=None, require_tpu: bool = True) -> int:
                     "compiles": w.compiles_in_window}
         out[key].update({m: readers[m]({"window": w}) for m in readers})
         hp.engine.done.clear()
-    path = str(sorted(trace_dir.glob("**/*.xplane.pb"))[-1])
-    trace = trace_reduce.load_xplane(path)
-    spans = load_program_spans(path)
+    trace = trace_reduce.load_xplane(
+        str(sorted(trace_dir.glob("**/*.xplane.pb"))[-1]))
+    spans = trace["program_spans"]
     shutil.rmtree(trace_dir, ignore_errors=True)
     window = trace_reduce.window_of(trace)
     red = trace_reduce.reduce(trace, window)
@@ -230,7 +213,7 @@ def main(argv=None, require_tpu: bool = True) -> int:
         "idle_s_outside_steps": (red["window_s"] - red["busy_s"] - sum(
             idle_inside(trace, spans, STEP, window))
             if red["devices"] else None),
-        "gaps": labelled_gaps(trace, spans)})
+        "gaps": red["gaps"]})
     print(json.dumps(out), flush=True)
     return 0
 

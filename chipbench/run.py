@@ -262,10 +262,13 @@ def main(argv=None, require_tpu: bool = True) -> int:
     if args.trace:
         import trace_reduce
         files = sorted(trace_dir.glob("**/*.xplane.pb"))
-        red = trace_reduce.reduce(trace_reduce.load_xplane(str(files[-1])))
+        events = trace_reduce.load_xplane(str(files[-1]))
         shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = {"trace": red, "window": w, "be_spans": be_spans,
-               "decodes": w.decodes, "cfg": cell["cfg"],
+        lo, hi = trace_reduce.window_of(events)
+        red = trace_reduce.reduce(events, (lo, hi))
+        ctx = {"trace": red, "events": events, "program_spans": [
+                   s for s in events["program_spans"] if lo <= s[1] < hi],
+               "window": w, "be_spans": be_spans, "cfg": cell["cfg"],
                "be_cfg": cell["be_cfg"], "job": cell["job"], "workload": wl,
                "mix": cell["mix"], "peaks": bench.peaks(devices[0].device_kind)}
         out["metrics"] = {}

@@ -2,8 +2,12 @@
 
 A cell ``<name>`` is ``workloads/<name>.json``; it names a configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<traffic>.json``).
-``BENCHMARK.json`` at the root above this directory says which metrics a
-cell reports. Nothing here lists cells, so a new cell is new files only.
+A configuration names its model family (``families/<family>.py``) and its
+plain reference (``reference/<reference>.py``), and a metric is
+``metrics/<metric>.py``. ``BENCHMARK.json`` at the root above this
+directory says which metrics a cell reports. Nothing here lists cells,
+families or metrics, so a new cell, even of a new family, is new files
+only.
 """
 from __future__ import annotations
 

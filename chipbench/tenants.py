@@ -15,9 +15,9 @@ import time
 from typing import List, Tuple
 
 import jax
-import jax.numpy as jnp
 
 import check
+import families
 import generator
 import weights
 
@@ -26,31 +26,7 @@ BATCHES = 64      # BE batches made in set-up; later steps cycle through them
 
 def model_config(cfg: dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig, SSMConfig
-    c = cfg["config"]
-    common = dict(name=cfg["name"], vocab_size=weights.embedding_rows(cfg),
-                  dtype=jnp.dtype(cfg["dtype"]).type,
-                  param_dtype=jnp.dtype(cfg["param_dtype"]).type,
-                  source=cfg["source"])
-    if cfg["family"] == "ssm":
-        s = c["ssm_cfg"]
-        nh = s["expand"] * c["d_model"] // s["headdim"]
-        return ModelConfig(
-            family="ssm", num_layers=c["n_layer"], d_model=c["d_model"],
-            num_heads=nh, num_kv_heads=nh, d_ff=0,
-            tie_embeddings=c["tie_embeddings"], rms_eps=c["norm_epsilon"],
-            ssm=SSMConfig(d_state=s["d_state"], expand=s["expand"],
-                          head_dim=s["headdim"], conv_kernel=s["d_conv"],
-                          chunk_size=s["chunk_size"]), **common)
-    if cfg["family"] == "dense":
-        return ModelConfig(
-            family="dense", num_layers=c["num_hidden_layers"],
-            d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
-            num_kv_heads=c["num_key_value_heads"],
-            d_ff=c["intermediate_size"], head_dim=c["head_dim"],
-            rope_theta=c["rope_theta"], rms_eps=c["rms_norm_eps"],
-            tie_embeddings=c["tie_word_embeddings"], **common)
-    raise ValueError(f"unknown family {cfg['family']!r}")
+    return families.of(cfg).model_config(cfg)
 
 
 def _same_layout(cfg: dict, model) -> None:

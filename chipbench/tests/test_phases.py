@@ -67,16 +67,39 @@ def test_idle_is_averaged_over_the_devices_that_ran():
     assert phases.step_idle_ms(t, _spans(), W) is None
 
 
+def _with_engine_spans(trace, spans):
+    """The trace as ``trace_reduce.load_xplane`` gives it when the host
+    plane also holds the engine's spans."""
+    return dict(trace, spans=trace["spans"] + [s[:3] for s in spans],
+                program_spans=spans)
+
+
 def test_gaps_are_named_by_the_innermost_engine_span():
-    gaps = phases.labelled_gaps(_trace(), _spans())
+    gaps = tr.reduce(_with_engine_spans(_trace(), _spans()))["gaps"]
     # gaps 24-40, 0-10, 90-100, 15-20 (middle 17.5: inside the emit loop of
     # the first step, which the benchmark's engine_step 9-18 holds)
     assert [(n, pytest.approx(s)) for n, s in gaps] == [
         ("chipbench.wait_arrival", 0.016), ("outside_spans", 0.010),
         ("outside_spans", 0.010), (P + "emit", 0.005)]
-    # of two spans equally long, the engine's names the gap
+    # of two spans equally long, the engine's names the gap, wherever it
+    # lies in the list
     same = [(P + "step", 9 * MS, 9 * MS, {})]
-    assert phases.labelled_gaps(_trace(), same)[3][0] == P + "step"
+    assert tr.reduce(_with_engine_spans(_trace(), same))["gaps"][3][0] == \
+        P + "step"
+    assert tr.label([(P + "step", 9 * MS, 9 * MS)]
+                    + _trace()["spans"], 17.5 * MS) == P + "step"
+
+
+def test_step_idle_reader_by_hand():
+    """``hp_step_idle_ms`` reads the mean idle inside the two steps of the
+    window that decoded; the BE step and the step after the window do not
+    count, and a trace with no device gives nothing."""
+    read = spec.Bench().metric_reader("hp_step_idle_ms")
+    ctx = {"events": _with_engine_spans(_trace(), _spans()),
+           "program_spans": _spans()}
+    assert read(ctx) == pytest.approx((3.1 + 1.8) / 2)
+    ctx["events"] = dict(ctx["events"], devices={})
+    assert read(ctx) is None
 
 
 def _fixture():
@@ -95,10 +118,12 @@ def test_the_existing_reduction_and_readers_are_unchanged():
           (P + "decode", lo + 11 * MS, 1 * MS, {"active": 4,
                                                 "kv_tokens": 900})]
     phases.idle_by_phase(trace, sp, (lo, hi))
-    phases.labelled_gaps(trace, sp)
+    phases.step_idle_ms(trace, sp, (lo, hi))
     assert trace == before and tr.reduce(trace) == red
     assert red["busy_s"] == pytest.approx(fx["hand"]["busy_s"], rel=1e-9)
-    assert phases.labelled_gaps(trace, []) == red["gaps"]
+    merged = tr.reduce(_with_engine_spans(trace, sp))
+    assert {k: v for k, v in merged.items() if k != "gaps"} == \
+        {k: v for k, v in red.items() if k != "gaps"}
     bench = spec.Bench()
     ctx = {"trace": red, "program_spans": sp}
     for name in ("hp_decode_ms", "hp_prefill_ms", "be_step_ms",

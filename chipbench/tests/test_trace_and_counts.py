@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import json
 import math
-from types import SimpleNamespace
 
 import pytest
 
@@ -90,12 +89,15 @@ def test_matmul_params_by_hand():
 def test_decode_least_and_shares_stay_under_100():
     cfg = _cfg("mamba2-130m")
     P = flops.matmul_params(cfg)
-    f, b = flops.decode_least(cfg, active=16, kv_tokens=0)
+    f, b = flops.decode_least(cfg, {"active": 16, "kv_tokens": 0})
     state = 24 * 64 * 128 + 3 * (1536 + 256)
     assert f == 2 * P * 16 + 4 * 24 * 64 * 128 * 24 * 16
     assert b == 2 * P + 2 * 2 * state * 24 * 16
     dense = _cfg("mistral-nemo-12b.pp10")
-    f, b = flops.decode_least(dense, active=2, kv_tokens=1000)
+    # the span's kv_tokens leave out the token each slot decodes: 2 slots
+    # holding 998 attend over 1000 positions
+    step = {"active": 2, "kv_tokens": 998}
+    f, b = flops.decode_least(dense, step)
     Pd = flops.matmul_params(dense)
     assert f == 2 * Pd * 2 + 4 * 32 * 128 * 1000 * 4
     assert b == 2 * Pd + 2 * 2 * 8 * 128 * 1000 * 4
@@ -105,7 +107,8 @@ def test_decode_least_and_shares_stay_under_100():
     least = max(f / pk["flops_bf16"], b / pk["hbm_bytes_per_s"])
     ctx = {"trace": {"programs": {"jit__decode_impl": {
         "count": 3, "seconds": 3 * least}}},
-        "decodes": [SimpleNamespace(active=2, kv_tokens=1000)] * 5,
+        "program_spans": [("tally.serve.step", 0, 9, {})]
+        + [("tally.serve.decode", 0, 1, step)] * 5,
         "cfg": dense, "peaks": pk}
     assert bench.metric_reader("hp_decode_roofline")(ctx) == \
         pytest.approx(100.0)

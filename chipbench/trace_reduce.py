@@ -2,9 +2,11 @@
 
 ``load_xplane`` reads the ``.xplane.pb`` that ``jax.profiler`` writes, with
 ``jax.profiler.ProfileData``, into plain lists: the ``XLA Modules`` line of
-each device plane (``/device:TPU:<n>``; one event per program execution)
-and the host's ``chipbench.*`` spans. The per-op line (a million events in
-a few seconds of decoding) is not read. ``reduce`` works on those lists
+each device plane (``/device:TPU:<n>``; one event per program execution),
+the host's ``chipbench.*`` spans (the benchmark's) and ``tally.*`` spans
+(the engine's, ``ServingEngine.step``'s phases), the engine's also with
+their stats as ``program_spans``. The per-op line (a million events in a
+few seconds of decoding) is not read. ``reduce`` works on those lists
 only, so a small recorded trace kept as JSON tests it.
 
 - busy: the union of the program executions' intervals on each device,
@@ -13,7 +15,8 @@ only, so a small recorded trace kept as JSON tests it.
 - programs: per program name (the jit name without its ``(id)``), the
   executions that started in the window and their summed device seconds;
 - gaps: the stretches of the window with no program on the device, longest
-  first, each labelled by the innermost benchmark span around its middle.
+  first, each labelled by the innermost span around its middle, the
+  engine's where an engine span and a benchmark span are equally long.
 """
 from __future__ import annotations
 
@@ -21,7 +24,10 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 Event = Tuple[str, float, float]           # name, start_ns, duration_ns
-WINDOW_SPAN = "chipbench.window"
+ProgramSpan = Tuple[str, float, float, dict]   # ... and the span's stats
+BENCH_PREFIX = "chipbench."
+PROGRAM_PREFIX = "tally."
+WINDOW_SPAN = BENCH_PREFIX + "window"
 _ID = re.compile(r"\(\d+\)$")
 
 
@@ -30,6 +36,7 @@ def load_xplane(path: str) -> dict:
     pd = ProfileData.from_file(path)
     devices: Dict[str, Dict[str, List[Event]]] = {}
     spans: List[Event] = []
+    program: List[ProgramSpan] = []
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:") and \
                 plane.name[len("/device:TPU:"):].isdigit():
@@ -41,10 +48,16 @@ def load_xplane(path: str) -> dict:
             devices[plane.name] = lines
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans += [(e.name, e.start_ns, e.duration_ns)
-                          for e in line.events
-                          if e.name.startswith("chipbench.")]
-    return {"devices": devices, "spans": spans}
+                for e in line.events:
+                    if e.name.startswith(BENCH_PREFIX):
+                        spans.append((e.name, e.start_ns, e.duration_ns))
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        program.append((e.name, e.start_ns, e.duration_ns,
+                                        dict(e.stats)))
+    program.sort(key=lambda s: s[1])
+    return {"devices": devices,
+            "spans": spans + [(n, s, d) for n, s, d, _ in program],
+            "program_spans": program}
 
 
 def program_name(event_name: str) -> str:
@@ -73,13 +86,15 @@ def window_of(trace: dict) -> Tuple[float, float]:
 
 
 def label(spans: List[Event], t: float) -> str:
-    """The innermost (shortest) benchmark span holding ``t``."""
-    best: Optional[Tuple[float, str]] = None
+    """The innermost (shortest) span holding ``t``; of two equally long,
+    the engine's."""
+    best: Optional[Tuple[float, bool, str]] = None
     for n, s, d in spans:
-        if s <= t < s + d and n != WINDOW_SPAN and (best is None
-                                                    or d < best[0]):
-            best = (d, n)
-    return best[1] if best else "outside_spans"
+        if s <= t < s + d and n != WINDOW_SPAN:
+            key = (d, not n.startswith(PROGRAM_PREFIX), n)
+            if best is None or key[:2] < best[:2]:
+                best = key
+    return best[2] if best else "outside_spans"
 
 
 def reduce(trace: dict, window: Optional[Tuple[float, float]] = None,

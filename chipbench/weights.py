@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import families
+
 LOGIT_STD = 3.0
 
 
@@ -37,58 +39,10 @@ def embedding_rows(cfg: dict) -> int:
 Spec = Tuple[Tuple[int, ...], str, float]
 
 
-def _ssm_layer(c: dict, L: int) -> Dict[str, Spec]:
-    d, s = c["d_model"], c["ssm_cfg"]
-    d_in = s["expand"] * d
-    nh = d_in // s["headdim"]
-    k, ds = s["d_conv"], s["d_state"]
-    out = 1.0 / math.sqrt(d_in) / math.sqrt(2 * L)
-    return {
-        "wz": ((L, d, d_in), "normal", 1 / math.sqrt(d)),
-        "wx": ((L, d, d_in), "normal", 1 / math.sqrt(d)),
-        "wB": ((L, d, ds), "normal", 1 / math.sqrt(d)),
-        "wC": ((L, d, ds), "normal", 1 / math.sqrt(d)),
-        "wdt": ((L, d, nh), "normal", 1 / math.sqrt(d)),
-        "conv_x": ((L, k, d_in), "normal", 1 / math.sqrt(k)),
-        "conv_B": ((L, k, ds), "normal", 1 / math.sqrt(k)),
-        "conv_C": ((L, k, ds), "normal", 1 / math.sqrt(k)),
-        "A_log": ((L, nh), "a_log", 0.0),
-        "D": ((L, nh), "ones", 0.0),
-        "dt_bias": ((L, nh), "dt_bias", 0.0),
-        "norm": ((L, d_in), "ones", 0.0),
-        "out_proj": ((L, d_in, d), "normal", out),
-    }
-
-
-def param_specs(cfg: dict) -> Dict:
-    """The parameter tree of ``cfg`` as specs, in the program's layout."""
-    c = cfg["config"]
+def lm_specs(cfg: dict, layer: Dict, E: int, tied: bool) -> Dict:
+    """A language model's tree around the specs of its layer stack: the
+    embedding, the final norm and, unless tied, the output head."""
     V = embedding_rows(cfg)
-    if cfg["family"] == "ssm":
-        L, E = c["n_layer"], c["d_model"]
-        layer = {"ln1": ((L, E), "ones", 0.0), "ssm": _ssm_layer(c, L)}
-        tied = c["tie_embeddings"]
-    elif cfg["family"] == "dense":
-        L, E = c["num_hidden_layers"], c["hidden_size"]
-        H, KV, D = (c["num_attention_heads"], c["num_key_value_heads"],
-                    c["head_dim"])
-        F = c["intermediate_size"]
-        shrink = 1.0 / math.sqrt(2 * L)
-        layer = {
-            "ln1": ((L, E), "ones", 0.0),
-            "attn": {"wq": ((L, E, H, D), "normal", 1 / math.sqrt(E)),
-                     "wk": ((L, E, KV, D), "normal", 1 / math.sqrt(E)),
-                     "wv": ((L, E, KV, D), "normal", 1 / math.sqrt(E)),
-                     "wo": ((L, H, D, E), "normal",
-                            shrink / math.sqrt(H * D))},
-            "ln2": ((L, E), "ones", 0.0),
-            "ffn": {"wi": ((L, E, F), "normal", 1 / math.sqrt(E)),
-                    "wg": ((L, E, F), "normal", 1 / math.sqrt(E)),
-                    "wo": ((L, F, E), "normal", shrink / math.sqrt(F))},
-        }
-        tied = c["tie_word_embeddings"]
-    else:
-        raise ValueError(f"unknown family {cfg['family']!r}")
     specs = {"embed": ((V, E), "normal", LOGIT_STD / math.sqrt(E) if tied
                        else 1.0),
              "final_norm": ((E,), "ones", 0.0),
@@ -96,6 +50,11 @@ def param_specs(cfg: dict) -> Dict:
     if not tied:
         specs["lm_head"] = ((E, V), "normal", LOGIT_STD / math.sqrt(E))
     return specs
+
+
+def param_specs(cfg: dict) -> Dict:
+    """The parameter tree of ``cfg`` as specs, in the program's layout."""
+    return families.of(cfg).param_specs(cfg)
 
 
 def _is_spec(x) -> bool:
